@@ -93,12 +93,9 @@ class MeshRuntime:
         # deterministic: bindings sorted by mesh coordinate get devices in order
         for dev, b in zip(devs, sorted(spec.bindings, key=lambda b: b.mesh_coord)):
             arr[b.mesh_coord] = dev
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:  # jax >= 0.5 explicit-sharding API
-            mesh = jax.sharding.Mesh(arr, spec.axis_names,
-                                     axis_types=(axis_type.Auto,) * len(spec.axis_names))
-        else:
-            mesh = jax.sharding.Mesh(arr, spec.axis_names)
+        mesh = jax.sharding.Mesh(
+            arr, spec.axis_names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(spec.axis_names))
         self._executed.append(spec)
         return mesh
 

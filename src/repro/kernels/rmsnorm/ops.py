@@ -1,15 +1,11 @@
-"""Jit'd wrapper for the fused RMSNorm kernel."""
+"""Wrapper for the fused RMSNorm kernel (``interpret=True`` off-TPU)."""
 
 from __future__ import annotations
 
-import jax
-
+from .. import check_backend
 from .rmsnorm import rmsnorm_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def rmsnorm(x, scale, eps: float = 1e-6):
-    return rmsnorm_fwd(x, scale, eps, interpret=not _on_tpu())
+def rmsnorm(x, scale, eps: float = 1e-6, *, interpret: bool = False):
+    check_backend(interpret)
+    return rmsnorm_fwd(x, scale, eps, interpret=interpret)

@@ -1,15 +1,11 @@
-"""Jit'd wrapper for the SSD chunk kernel (interpret mode off-TPU)."""
+"""Wrapper for the SSD chunk kernel (``interpret=True`` off-TPU)."""
 
 from __future__ import annotations
 
-import jax
-
+from .. import check_backend
 from .ssd_scan import ssd_chunk_fwd
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
-def ssd_chunk(C, B, x, dt, da):
-    return ssd_chunk_fwd(C, B, x, dt, da, interpret=not _on_tpu())
+def ssd_chunk(C, B, x, dt, da, *, interpret: bool = False):
+    check_backend(interpret)
+    return ssd_chunk_fwd(C, B, x, dt, da, interpret=interpret)

@@ -30,12 +30,13 @@ from ..configs import SHAPES, ShapeSpec, input_specs, shape_applicable
 from ..configs.registry import ARCHS, get_config
 from ..models import lm
 from ..models.config import ModelConfig
-from ..parallel.sharding import (ShardingRules, logical_to_pspec,
-                                 param_shardings, use_rules)
+from ..parallel.sharding import (ShardingRules, batch_shardings,
+                                 logical_to_pspec, param_shardings, use_rules)
 from ..roofline.hlo import collective_bytes_by_kind
 from ..train.optimizer import Adafactor, AdamW
 from ..train.schedule import cosine_schedule
-from ..train.train_step import StepConfig, make_train_step, train_state_specs
+from ..train.train_step import (StepConfig, abstract_train_state,
+                                make_train_step, train_state_shardings)
 from .mesh import make_planned_mesh
 
 BIG_MODEL_PARAMS = 60e9   # adafactor above this (HBM), adamw below
@@ -66,16 +67,6 @@ def pick_optimizer(cfg: ModelConfig):
     if cfg.param_count() >= BIG_MODEL_PARAMS:
         return Adafactor(lr)
     return AdamW(lr)
-
-
-def batch_shardings(specs: Dict[str, Any], rules: ShardingRules):
-    from jax.sharding import NamedSharding
-
-    def shard_one(s: jax.ShapeDtypeStruct):
-        axes = ["batch"] + [None] * (len(s.shape) - 1)
-        return NamedSharding(rules.mesh, logical_to_pspec(axes, rules, s.shape))
-
-    return jax.tree.map(shard_one, specs)
 
 
 def cache_shardings(cache_abs: Any, rules: ShardingRules):
@@ -119,16 +110,8 @@ def _compile_once(cfg: ModelConfig, shape: ShapeSpec, mesh, rules,
             sc = StepConfig(**{**sc.__dict__, "unroll": u,
                                "micro_unroll": unroll})
             step = make_train_step(cfg, opt, sc)
-            from ..train.train_step import abstract_train_state
             state_abs = abstract_train_state(cfg, opt)
-            specs = train_state_specs(cfg, opt)
-            state_sh = {
-                "params": param_shardings(specs["params"], rules,
-                                          state_abs["params"]),
-                "opt_state": param_shardings(specs["opt_state"], rules,
-                                             state_abs["opt_state"]),
-                "step": NamedSharding(mesh, P()),
-            }
+            state_sh = train_state_shardings(cfg, opt, rules)
             in_specs = input_specs(cfg, shape)
             batch_sh = batch_shardings(in_specs, rules)
             jitted = jax.jit(step, in_shardings=(state_sh, batch_sh),

@@ -25,11 +25,8 @@ def make_production_mesh(*, multi_pod: bool = False):
     import jax
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:  # jax >= 0.5 explicit-sharding API
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def mesh_axis_specs(multi_pod: bool = False):

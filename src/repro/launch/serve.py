@@ -3,6 +3,9 @@
   PYTHONPATH=src python -m repro.launch.serve --arch mamba2-780m --smoke \
       --requests 8 --new-tokens 16
 
+``main(argv)`` can also be called in-process; it returns the report it
+prints.
+
 With ``--replicas N`` (N > 1) requests go through the front-end
 :class:`~repro.serve.router.Router`: load-aware dispatch across N
 engine replicas with bounded per-replica queues, and the run report
@@ -26,15 +29,16 @@ import math
 import time
 
 
-def provision_replicas(slots: int, chips_per_replica: int,
+def provision_replicas(replicas: int, chips_per_replica: int,
                        state_dir: str = None, reconcile_mode: str = "threaded",
                        node_plane: bool = False):
     """Declarative serve replica set -> (plane, workload ApiObject).
 
     With ``state_dir``, an existing WAL is recovered first: the stamped
     replica claims are adopted with their allocations intact and the
-    workload only converges on a *delta* (e.g. a changed ``slots``) —
+    workload only converges on a *delta* (e.g. a changed ``replicas``) —
     the restart-safe serving story of the durable control plane.
+    One claim is stamped per replica.
 
     ``reconcile_mode="threaded"`` (default) starts a
     :class:`~repro.api.runtime.ControlPlaneRuntime` whose informer
@@ -52,7 +56,7 @@ def provision_replicas(slots: int, chips_per_replica: int,
     from ..api import ControlPlane, ControlPlaneRuntime, Workload
     from ..topology.tpu import TpuPodSpec, build_tpu_cluster
 
-    need = slots * chips_per_replica
+    need = replicas * chips_per_replica
     side = max(2, 2 * math.ceil(math.sqrt(need) / 2))  # even torus side
     cluster = build_tpu_cluster(1, TpuPodSpec(x=side, y=side))
     reg = core.DriverRegistry()
@@ -75,23 +79,26 @@ def provision_replicas(slots: int, chips_per_replica: int,
     wl_obj = plane.store.try_get("Workload", "serve")
     if wl_obj is None:
         plane.submit(Workload(claim_template="serve-replica", role="serve",
-                              replicas=slots),
+                              replicas=replicas),
                      name="serve")
-    elif wl_obj.spec.replicas != slots:
+    elif wl_obj.spec.replicas != replicas:
         # resize of a recovered replica set is a spec edit, as ever
         plane.edit("Workload", "serve",
-                   lambda w: setattr(w, "replicas", slots))
+                   lambda w: setattr(w, "replicas", replicas))
     wl = plane.wait_for("Workload", "serve")
     return plane, wl
 
 
-def main() -> None:
+def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-1.8b")
     ap.add_argument("--smoke", action="store_true")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--slots", type=int, default=4)
     ap.add_argument("--prompt-len", type=int, default=12)
+    ap.add_argument("--prompt-len-max", type=int, default=0,
+                    help=">0 draws each prompt length uniformly from "
+                         "[--prompt-len, --prompt-len-max]")
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--max-len", type=int, default=128)
     ap.add_argument("--temperature", type=float, default=0.0)
@@ -121,7 +128,7 @@ def main() -> None:
     ap.add_argument("--obs-dir", default=None,
                     help="write metrics.prom/metrics.json/spans.json "
                          "here at exit (scripts/obsctl.py reads them)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     obs_tracer = None
     if args.obs_dir:
@@ -132,7 +139,7 @@ def main() -> None:
     knd = None
     plane = None
     if args.claim_chips > 0:
-        plane, wl = provision_replicas(args.slots, args.claim_chips,
+        plane, wl = provision_replicas(args.replicas, args.claim_chips,
                                        state_dir=args.state_dir,
                                        reconcile_mode=args.reconcile_mode,
                                        node_plane=args.node_plane)
@@ -145,6 +152,8 @@ def main() -> None:
         knd = {"replica_claims": claims,
                "submit_to_ready_ms": round(lat["total"] * 1e3, 2)}
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
     import numpy as np
 
@@ -173,7 +182,9 @@ def main() -> None:
     t0 = time.time()
     finished = []
     for _ in range(args.requests):
-        prompt = rng.randint(0, cfg.vocab_size, size=args.prompt_len).tolist()
+        n = (rng.randint(args.prompt_len, args.prompt_len_max + 1)
+             if args.prompt_len_max > 0 else args.prompt_len)
+        prompt = rng.randint(0, cfg.vocab_size, size=n).tolist()
         try:
             router.submit(prompt, args.new_tokens, args.temperature)
         except RouterOverloadError:
@@ -185,8 +196,11 @@ def main() -> None:
     failures = [r for r in finished if r.failed]
     total_tokens = sum(len(r.generated) for r in done)
     baseline = slo.arm_snapshot("baseline")
+    dev = jax.devices()[0]
     out = {
         "arch": cfg.name,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
         "replicas": len(replica_names),
         "completed": len(done),
         "failed": len(failures),
@@ -217,6 +231,7 @@ def main() -> None:
         obs_tracer.detach()
         out["obs"] = dump_artifacts(args.obs_dir, tracer=obs_tracer)
     print(json.dumps(out, indent=1))
+    return out
 
 
 if __name__ == "__main__":

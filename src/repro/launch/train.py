@@ -8,6 +8,10 @@ real v5e pod consumes the production mesh.
 
   PYTHONPATH=src python -m repro.launch.train --arch mamba2-780m --smoke \
       --steps 50 --batch 8 --seq 64
+
+``main(argv)`` can also be called in-process; it returns the report it
+prints, with every step's loss and each device's share of the train
+state in bytes.
 """
 
 from __future__ import annotations
@@ -18,7 +22,7 @@ import os
 import time
 
 
-def main() -> None:
+def main(argv=None) -> dict:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="h2o-danube-1.8b")
     ap.add_argument("--smoke", action="store_true",
@@ -57,7 +61,7 @@ def main() -> None:
     ap.add_argument("--obs-dir", default=None,
                     help="write metrics.prom/metrics.json/spans.json "
                          "here at exit (scripts/obsctl.py reads them)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
     obs_tracer = None
     if args.obs_dir:
@@ -69,6 +73,8 @@ def main() -> None:
         os.environ["XLA_FLAGS"] = (
             f"--xla_force_host_platform_device_count={args.devices}")
 
+    from .compile_cache import enable_compile_cache
+    enable_compile_cache()
     import jax
 
     from ..ckpt.checkpoint import CheckpointManager
@@ -196,12 +202,24 @@ def main() -> None:
         print(f"[obs] artifacts: {', '.join(sorted(paths.values()))}")
 
     losses = [h["loss"] for h in trainer.history]
-    print(json.dumps({
+    state_bytes: dict = {}
+    for leaf in jax.tree.leaves(trainer.state):
+        for shard in leaf.addressable_shards:
+            key = str(shard.device.id)
+            state_bytes[key] = state_bytes.get(key, 0) + shard.data.nbytes
+    dev = jax.devices()[0]
+    report = {
         "arch": cfg.name, "result": out,
+        "device": {"platform": dev.platform, "kind": dev.device_kind,
+                   "count": len(jax.devices())},
+        "losses": losses,
         "loss_first": losses[0] if losses else None,
         "loss_last": losses[-1] if losses else None,
         "steps_per_s": round(len(losses) / dt, 3) if dt > 0 else None,
-    }, indent=1))
+        "state_bytes_per_device": state_bytes,
+    }
+    print(json.dumps(report, indent=1))
+    return report
 
 
 if __name__ == "__main__":

@@ -75,15 +75,6 @@ def make_compressed_grad_sync(mesh: Mesh, grad_fn, axis: str = "pod"):
         metrics = jax.tree.map(lambda m: jax.lax.pmean(m, axis), metrics)
         return grads, new_err, metrics
 
-    in_specs = (P(), P(axis), P())
-    out_specs = (P(), P(), P())
-    if hasattr(jax, "shard_map"):  # jax >= 0.6 top-level API
-        return jax.shard_map(per_pod, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names={axis},
-                             check_vma=False)
-    # older jax: partial-manual via auto= (everything but the pod axis)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(per_pod, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs,
-                     auto=frozenset(mesh.axis_names) - {axis},
-                     check_rep=False)
+    return jax.shard_map(per_pod, mesh=mesh, in_specs=(P(), P(axis), P()),
+                         out_specs=(P(), P(), P()), axis_names={axis},
+                         check_vma=False)

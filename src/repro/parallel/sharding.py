@@ -23,7 +23,8 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["ShardingRules", "use_rules", "current_rules", "constrain",
-           "logical_to_pspec", "param_shardings", "BASE_RULES"]
+           "logical_to_pspec", "param_shardings", "batch_shardings",
+           "BASE_RULES"]
 
 MeshAxes = Union[None, str, Tuple[str, ...]]
 
@@ -180,3 +181,13 @@ def param_shardings(spec_tree: Any, rules: ShardingRules,
     out = [NamedSharding(rules.mesh, logical_to_pspec(axes, rules, a.shape))
            for a, axes in zip(flat_abs, flat_spec)]
     return treedef.unflatten(out)
+
+
+def batch_shardings(batch: Any, rules: ShardingRules) -> Any:
+    """NamedShardings for a batch pytree: dim 0 is "batch", the rest
+    replicate. Leaves need only a ``shape`` (arrays or ShapeDtypeStructs)."""
+    def shard_one(x):
+        axes = ["batch"] + [None] * (len(x.shape) - 1)
+        return NamedSharding(rules.mesh, logical_to_pspec(axes, rules, x.shape))
+
+    return jax.tree.map(shard_one, batch)

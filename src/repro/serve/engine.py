@@ -98,10 +98,11 @@ _JIT_STEPS: Dict[Any, Any] = {}
 def _jitted_step(cfg: ModelConfig):
     fn = _JIT_STEPS.get(cfg)
     if fn is None:
-        fn = jax.jit(
-            lambda p, t, c, bt, pos, adv, zb, rs: lm.decode_chunk(
-                cfg, p, t, c, bt, pos, adv, zero_blocks=zb, reset_slots=rs),
-            donate_argnums=(2,))
+        def serve_decode_chunk(p, t, c, bt, pos, adv, zb, rs):
+            return lm.decode_chunk(cfg, p, t, c, bt, pos, adv,
+                                   zero_blocks=zb, reset_slots=rs)
+
+        fn = jax.jit(serve_decode_chunk, donate_argnums=(2,))
         _JIT_STEPS[cfg] = fn
     return fn
 

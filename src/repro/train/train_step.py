@@ -20,9 +20,11 @@ from jax import lax
 
 from ..models import lm
 from ..models.config import ModelConfig
+from ..parallel.sharding import ShardingRules, param_shardings
 from .optimizer import Optimizer, clip_by_global_norm
 
-__all__ = ["TrainState", "make_train_step", "init_train_state"]
+__all__ = ["TrainState", "make_train_step", "init_train_state",
+           "train_state_shardings"]
 
 
 @dataclass
@@ -60,6 +62,19 @@ def train_state_specs(cfg: ModelConfig, optimizer: Optimizer) -> TrainState:
     return {"params": pspecs,
             "opt_state": optimizer.state_specs(pspecs, lm.abstract_params(cfg)),
             "step": ()}
+
+
+def train_state_shardings(cfg: ModelConfig, optimizer: Optimizer,
+                          rules: ShardingRules) -> TrainState:
+    """NamedShardings for the whole train state on ``rules.mesh``."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    state_abs = abstract_train_state(cfg, optimizer)
+    specs = train_state_specs(cfg, optimizer)
+    return {"params": param_shardings(specs["params"], rules,
+                                      state_abs["params"]),
+            "opt_state": param_shardings(specs["opt_state"], rules,
+                                         state_abs["opt_state"]),
+            "step": NamedSharding(rules.mesh, P())}
 
 
 def make_train_step(cfg: ModelConfig, optimizer: Optimizer,

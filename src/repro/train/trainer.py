@@ -27,8 +27,10 @@ from ..core.drivers import KNDDriver
 from ..core.nri import Event, EventBus, Events
 from ..data.pipeline import SyntheticLMData
 from ..models.config import ModelConfig
+from ..parallel.sharding import batch_shardings, current_rules
 from .optimizer import Optimizer
-from .train_step import StepConfig, TrainState, init_train_state, make_train_step
+from .train_step import (StepConfig, TrainState, init_train_state,
+                         make_train_step, train_state_shardings)
 
 __all__ = ["Trainer", "CheckpointDriver", "TelemetryDriver", "FaultInjector"]
 
@@ -130,6 +132,7 @@ class Trainer:
     state: Optional[TrainState] = None
     history: List[Dict[str, float]] = field(default_factory=list)
     _step_fn: Any = None
+    _batch_sh: Any = None
     _stop: bool = False
 
     def __post_init__(self) -> None:
@@ -146,11 +149,29 @@ class Trainer:
 
     # ------------------------------------------------------------------
     def init(self, seed: int = 0) -> None:
-        self.state = init_train_state(self.cfg, self.optimizer,
-                                      jax.random.PRNGKey(seed))
-        self._step_fn = jax.jit(make_train_step(
-            self.cfg, self.optimizer, self.step_cfg, self.grad_transform),
-            donate_argnums=(0,))
+        """Build the state and the jitted step. Under active sharding
+        rules with a mesh, the state is created directly into its
+        shardings (one jitted init, nothing built whole on one device)
+        and the step's inputs and outputs keep those shardings."""
+        key = jax.random.PRNGKey(seed)
+        step = make_train_step(self.cfg, self.optimizer, self.step_cfg,
+                               self.grad_transform)
+        rules = current_rules()
+        if rules is None or rules.mesh is None:
+            self._batch_sh = None
+            self.state = init_train_state(self.cfg, self.optimizer, key)
+            self._step_fn = jax.jit(step, donate_argnums=(0,))
+            return
+        state_sh = train_state_shardings(self.cfg, self.optimizer, rules)
+        self._batch_sh = batch_shardings(self.data.batch(0), rules)
+
+        def init_sharded_state(k):
+            return init_train_state(self.cfg, self.optimizer, k)
+
+        self.state = jax.jit(init_sharded_state, out_shardings=state_sh)(key)
+        self._step_fn = jax.jit(step, in_shardings=(state_sh, self._batch_sh),
+                                out_shardings=(state_sh, None),
+                                donate_argnums=(0,))
 
     def resume(self) -> int:
         """Restore newest committed checkpoint; returns the step."""
@@ -168,8 +189,7 @@ class Trainer:
             self.bus.publish(Events.STEP_BEGIN, step=step, bus=self.bus)
             if self._stop:
                 return {"stopped_at": step, "reason": "node_failure"}
-            batch = self.data.batch(step)
-            batch = {k: jax.numpy.asarray(v) for k, v in batch.items()}
+            batch = jax.device_put(self.data.batch(step), self._batch_sh)
             self.state, metrics = self._step_fn(self.state, batch)
             self.bus.publish(Events.STEP_END, step=step, metrics=metrics,
                              state=self.state, bus=self.bus)

@@ -1,4 +1,8 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (hypothesis)."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps (hypothesis).
+
+Every kernel call here asks for the Pallas interpreter explicitly; the
+last test checks that a call without it refuses to run off the TPU.
+"""
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +38,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (B, S, H, d), dtype)
         k = jax.random.normal(ks[1], (B, S, K, d), dtype)
         v = jax.random.normal(ks[2], (B, S, K, d), dtype)
-        out = flash_attention(q, k, v, causal, window, 64, 64)
+        out = flash_attention(q, k, v, causal, window, 64, 64, interpret=True)
         ref = attention_ref(q, k, v, causal=causal, window=window)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                     - ref.astype(jnp.float32))))
@@ -49,7 +53,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, s, h, d), jnp.float32)
         k = jax.random.normal(ks[1], (1, s, K, d), jnp.float32)
         v = jax.random.normal(ks[2], (1, s, K, d), jnp.float32)
-        out = flash_attention(q, k, v, True, 0, 32, 32)
+        out = flash_attention(q, k, v, True, 0, 32, 32, interpret=True)
         ref = attention_ref(q, k, v, causal=True)
         assert out.shape == q.shape
         assert float(jnp.max(jnp.abs(out - ref))) < 2e-5
@@ -60,7 +64,7 @@ class TestFlashAttention:
         q = jax.random.normal(ks[0], (1, 64, 2, 16), jnp.float32)
         k = jax.random.normal(ks[1], (1, 64, 2, 16), jnp.float32)
         v = jax.random.normal(ks[2], (1, 64, 2, 16), jnp.float32)
-        g1 = jax.grad(lambda q_: flash_attention(q_, k, v).sum())(q)
+        g1 = jax.grad(lambda q_: flash_attention(q_, k, v, interpret=True).sum())(q)
         g2 = jax.grad(lambda q_: attention_ref(q_, k, v).sum())(q)
         assert float(jnp.max(jnp.abs(g1 - g2))) < 1e-4
 
@@ -78,7 +82,7 @@ class TestSsdChunk:
         x = jax.random.normal(ks[2], (b, nc, Q, H, P))
         dt = jax.nn.softplus(jax.random.normal(ks[3], (b, nc, Q, H)))
         da = -jnp.abs(jax.random.normal(ks[4], (b, nc, Q, H))) * 0.1
-        outs = ssd_chunk(C, B, x, dt, da)
+        outs = ssd_chunk(C, B, x, dt, da, interpret=True)
         refs = ssd_chunk_ref(C, B, x, dt, da)
         for o, r in zip(outs, refs):
             assert float(jnp.max(jnp.abs(o - r))) < 1e-4
@@ -93,7 +97,7 @@ class TestSsdChunk:
         x = jax.random.normal(ks[2], (1, 2, Q, 2, P))
         dt = jax.nn.softplus(jax.random.normal(ks[3], (1, 2, Q, 2)))
         da = -jnp.abs(jax.random.normal(ks[4], (1, 2, Q, 2))) * 0.05
-        y, s, d = ssd_chunk(C, B, x, dt, da)
+        y, s, d = ssd_chunk(C, B, x, dt, da, interpret=True)
         yr, sr, dr = ssd_chunk_ref(C, B, x, dt, da)
         assert y.shape == (1, 2, Q, 2, P) and s.shape == (1, 2, 2, N, P)
         assert float(jnp.max(jnp.abs(y - yr))) < 1e-4
@@ -118,7 +122,7 @@ class TestRmsnorm:
     def test_matches_ref(self, dtype, shape):
         x = jax.random.normal(jax.random.PRNGKey(0), shape, dtype)
         sc = jax.random.normal(jax.random.PRNGKey(1), shape[-1:], jnp.float32)
-        out = rmsnorm(x, sc)
+        out = rmsnorm(x, sc, interpret=True)
         ref = rmsnorm_ref(x, sc)
         err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                     - ref.astype(jnp.float32))))
@@ -129,7 +133,21 @@ class TestRmsnorm:
     def test_property_rows(self, rows, d):
         x = jax.random.normal(jax.random.PRNGKey(rows), (rows, d), jnp.float32)
         sc = jnp.ones((d,))
-        out = rmsnorm(x, sc)
+        out = rmsnorm(x, sc, interpret=True)
         ref = rmsnorm_ref(x, sc)
         assert out.shape == x.shape
         assert float(jnp.max(jnp.abs(out - ref))) < 1e-5
+
+
+@pytest.mark.parametrize("call", [
+    lambda: flash_attention(*(jnp.ones((1, 16, 2, 16)),) * 3),
+    lambda: rmsnorm(jnp.ones((4, 128)), jnp.ones((128,))),
+    lambda: ssd_chunk(jnp.ones((1, 1, 8, 4)), jnp.ones((1, 1, 8, 4)),
+                      jnp.ones((1, 1, 8, 2, 8)), jnp.ones((1, 1, 8, 2)),
+                      jnp.ones((1, 1, 8, 2))),
+], ids=["flash_attention", "rmsnorm", "ssd_chunk"])
+def test_kernel_refuses_interpreter_unasked(call):
+    """Off the TPU a wrapper raises unless interpret=True was passed."""
+    assert jax.default_backend() != "tpu"
+    with pytest.raises(RuntimeError, match="interpret=True"):
+        call()

@@ -14,12 +14,8 @@ from repro.parallel.sharding import (BASE_RULES, ShardingRules,
 class TestLogicalToPspec:
     def setup_method(self):
         # a fake mesh via namespace: rules.resolve checks mesh axis names
-        axis_type = getattr(jax.sharding, "AxisType", None)
-        if axis_type is not None:  # jax >= 0.5 explicit-sharding API
-            self.mesh = jax.make_mesh((1,), ("model",),
-                                      axis_types=(axis_type.Auto,))
-        else:
-            self.mesh = jax.make_mesh((1,), ("model",))
+        self.mesh = jax.make_mesh((1,), ("model",),
+                                  axis_types=(jax.sharding.AxisType.Auto,))
 
     def test_missing_axis_dropped(self):
         rules = ShardingRules(mesh=self.mesh)
@@ -66,12 +62,8 @@ import jax, jax.numpy as jnp
 import numpy as np
 from repro.parallel.collectives import make_compressed_grad_sync, zeros_like_tree
 
-axis_type = getattr(jax.sharding, "AxisType", None)
-if axis_type is not None:
-    mesh = jax.make_mesh((2,2,2), ("pod","data","model"),
-                         axis_types=(axis_type.Auto,)*3)
-else:
-    mesh = jax.make_mesh((2,2,2), ("pod","data","model"))
+mesh = jax.make_mesh((2,2,2), ("pod","data","model"),
+                     axis_types=(jax.sharding.AxisType.Auto,)*3)
 def grad_fn(params, batch):
     def loss(p): return jnp.mean((batch["x"] @ p["w"] - batch["y"])**2)
     return jax.grad(loss)(params), {"loss": loss(params)}

@@ -146,6 +146,32 @@ class TestTrainerDrivers:
             out2 = t2.fit(2)
             assert out2["completed"] >= 10
 
+    def test_sharded_init_matches_unsharded(self):
+        """Under a mesh, init builds the state straight into the planned
+        shardings, and training matches the unsharded trainer."""
+        from jax.sharding import NamedSharding
+        from repro.parallel.sharding import ShardingRules, use_rules
+        cfg = smoke_config("h2o-danube-1.8b").replace(
+            param_dtype="float32", compute_dtype="float32")
+        mesh = jax.make_mesh((1, 1), ("data", "model"),
+                             axis_types=(jax.sharding.AxisType.Auto,) * 2)
+
+        def run(rules):
+            t = Trainer(cfg, AdamW(constant_schedule(1e-3)),
+                        SyntheticLMData(cfg, 4, 16),
+                        step_cfg=StepConfig(remat="none"))
+            with use_rules(rules):
+                t.init(0)
+                leaves = jax.tree.leaves(t.state)
+                t.fit(3)
+            return leaves, [h["loss"] for h in t.history]
+
+        sharded, l_sh = run(ShardingRules(mesh=mesh))
+        _, l_plain = run(None)
+        assert all(isinstance(a.sharding, NamedSharding)
+                   and a.sharding.mesh.shape == mesh.shape for a in sharded)
+        np.testing.assert_allclose(l_sh, l_plain, rtol=1e-5)
+
     def test_driver_isolation(self):
         """A crashing driver never breaks training (NRI isolation)."""
         from repro.core.drivers import KNDDriver
