@@ -305,59 +305,75 @@ def decode_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
     same way (state is cumulative: masking alone cannot protect it).
     Returns (logits (B,C,V...) , new cache); pos/block accounting stays
     with the host-side manager.
-    """
-    L = cfg.num_layers
-    if zero_blocks is not None and "kv" in cache:
-        cache = dict(cache)
-        cache["kv"] = {
-            "k": cache["kv"]["k"].at[:, zero_blocks].set(0.0, mode="drop"),
-            "v": cache["kv"]["v"].at[:, zero_blocks].set(0.0, mode="drop"),
-        }
-    if reset_slots is not None and "ssd" in cache:
-        cache = dict(cache)
-        cache["ssd"] = jax.tree.map(
-            lambda a: jnp.where(
-                reset_slots.reshape((1, -1) + (1,) * (a.ndim - 2)),
-                jnp.zeros((), a.dtype), a),
-            cache["ssd"])
 
-    x, _ = embed_tokens(cfg, params, {"tokens": tokens})
+    The ops carry ``jax.named_scope`` names in their ``op_name``
+    metadata (device profiles group by them): ``cache`` (the
+    zero-epoch and each layer's slice and update of the stacked pool
+    and state), ``attention``, ``ssd``, ``mlp`` and ``head`` (embedding,
+    final norm, LM head). A layer's norm goes with the block it feeds.
+    """
+    scope = jax.named_scope
+    L = cfg.num_layers
+    with scope("cache"):
+        if zero_blocks is not None and "kv" in cache:
+            cache = dict(cache)
+            cache["kv"] = {
+                "k": cache["kv"]["k"].at[:, zero_blocks].set(0.0, mode="drop"),
+                "v": cache["kv"]["v"].at[:, zero_blocks].set(0.0, mode="drop"),
+            }
+        if reset_slots is not None and "ssd" in cache:
+            cache = dict(cache)
+            cache["ssd"] = jax.tree.map(
+                lambda a: jnp.where(
+                    reset_slots.reshape((1, -1) + (1,) * (a.ndim - 2)),
+                    jnp.zeros((), a.dtype), a),
+                cache["ssd"])
+
+    with scope("head"):
+        x, _ = embed_tokens(cfg, params, {"tokens": tokens})
 
     def get_layer(tree, li):
-        return jax.tree.map(lambda a: lax.dynamic_index_in_dim(a, li, 0,
-                                                               keepdims=False),
-                            tree)
+        with scope("cache"):
+            return jax.tree.map(
+                lambda a: lax.dynamic_index_in_dim(a, li, 0, keepdims=False),
+                tree)
 
     def set_layer(tree, sub, li):
-        return jax.tree.map(
-            lambda a, s: lax.dynamic_update_index_in_dim(a, s.astype(a.dtype),
-                                                         li, 0),
-            tree, sub)
+        with scope("cache"):
+            return jax.tree.map(
+                lambda a, s: lax.dynamic_update_index_in_dim(
+                    a, s.astype(a.dtype), li, 0),
+                tree, sub)
 
     def body(carry, scan_in):
         h, kv_all, ssd_all = carry
         lp, li = scan_in
-        hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
         if cfg.family == "ssm":
-            y, new_ssd = ssd_decode_chunk(cfg, lp["ssd"], hn,
-                                          get_layer(ssd_all, li), adv)
+            ssd_l = get_layer(ssd_all, li)
+            with scope("ssd"):
+                hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
+                y, new_ssd = ssd_decode_chunk(cfg, lp["ssd"], hn, ssd_l, adv)
             ssd_all = set_layer(ssd_all, new_ssd, li)
             return (h + y, kv_all, ssd_all), None
-        att, new_kv = attention_decode_paged(cfg, lp["attn"], hn,
-                                             get_layer(kv_all, li),
-                                             block_table, pos, adv)
+        kv_l = get_layer(kv_all, li)
+        with scope("attention"):
+            hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
+            att, new_kv = attention_decode_paged(cfg, lp["attn"], hn, kv_l,
+                                                 block_table, pos, adv)
         kv_all = set_layer(kv_all, new_kv, li)
         if cfg.hybrid:
-            y2, new_ssd = ssd_decode_chunk(cfg, lp["ssd"], hn,
-                                           get_layer(ssd_all, li), adv)
+            ssd_l = get_layer(ssd_all, li)
+            with scope("ssd"):
+                y2, new_ssd = ssd_decode_chunk(cfg, lp["ssd"], hn, ssd_l, adv)
             ssd_all = set_layer(ssd_all, new_ssd, li)
             att = 0.5 * (att + y2)
         h = h + att
-        h2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
-        if cfg.num_experts > 0:
-            y, _ = moe_apply(cfg, lp["moe"], h2)
-        else:
-            y = mlp_apply(cfg, lp["mlp"], h2)
+        with scope("mlp"):
+            h2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
+            if cfg.num_experts > 0:
+                y, _ = moe_apply(cfg, lp["moe"], h2)
+            else:
+                y = mlp_apply(cfg, lp["mlp"], h2)
         return (h + y, kv_all, ssd_all), None
 
     kv0 = cache.get("kv", jnp.zeros((L, 1)))
@@ -366,8 +382,9 @@ def decode_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
         body, (x, kv0, ssd0),
         (params["layers"], jnp.arange(L, dtype=jnp.int32)),
         unroll=min(unroll, cfg.num_layers))
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = lm_head(cfg, params, x)
+    with scope("head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = lm_head(cfg, params, x)
     new_cache = dict(cache)
     if "kv" in cache:
         new_cache["kv"] = new_kv

@@ -7,7 +7,8 @@ See docs/OBSERVABILITY.md. Import surface:
   per-instance accumulator bound to the active registry.
 * registry — :class:`MetricsRegistry`, :func:`active` /
   :func:`install` / :func:`installed`, Prometheus/JSON exporters.
-* tracing — :class:`Tracer`, module-level :func:`emit`,
+* tracing — :class:`Tracer`, module-level :func:`emit` and
+  :func:`span` (a profiler span while a tracer is installed),
   :func:`install_tracer` / :func:`installed_tracer`,
   :func:`chrome_trace` / :func:`validate_spans` /
   :func:`spans_from_store`.
@@ -31,7 +32,7 @@ from .registry import (                                        # noqa: F401
     quantile)
 from .trace import (                                           # noqa: F401
     TRACKED_CONDITIONS, Span, Tracer, active_tracer, chrome_trace,
-    emit, install_tracer, installed_tracer, spans_from_store,
+    emit, install_tracer, installed_tracer, span, spans_from_store,
     validate_spans)
 
 METRICS_PROM = "metrics.prom"

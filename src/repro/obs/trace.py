@@ -11,6 +11,12 @@ The tracer has two feeds:
   calls the module-level :func:`emit`, which is a no-op unless a tracer
   is installed (same ``install``/``installed`` idiom as ``api/chaos``).
 
+:func:`span` is the interval form of an emit for hot loops (the serve
+tick's phases): under an installed tracer it opens a
+``jax.profiler.TraceAnnotation``, so the interval lands on the
+profiler's host plane on the same clock as the device's operations;
+with none installed it is a shared no-op context.
+
 :meth:`Tracer.spans` reconstructs per-object span trees:
 
 * claim/workload/node lifecycle — ``submit`` (ADDED) through each
@@ -36,13 +42,13 @@ from __future__ import annotations
 
 import json
 import threading
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from dataclasses import dataclass, field
 from time import monotonic
 from typing import Any, Dict, Iterator, List, Optional, Tuple
 
 __all__ = [
-    "TRACKED_CONDITIONS", "Span", "Tracer", "emit",
+    "TRACKED_CONDITIONS", "Span", "Tracer", "emit", "span",
     "install_tracer", "installed_tracer", "active_tracer",
     "chrome_trace", "validate_spans", "spans_from_store",
 ]
@@ -362,3 +368,17 @@ def emit(kind: str, name: str, event: str, **args: Any) -> None:
     t = _active
     if t is not None:
         t.emit(kind, name, event, **args)
+
+
+_NULL_SPAN = nullcontext()
+
+
+def span(name: str, **stats: Any):
+    """A profiler span: ``jax.profiler.TraceAnnotation(name, **stats)``
+    while a tracer is installed, else a shared no-op context (one
+    attribute load + None check, as for :func:`emit`). jax is imported
+    only on the traced path, so obs still imports nothing heavy."""
+    if _active is None:
+        return _NULL_SPAN
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name, **stats)

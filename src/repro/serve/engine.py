@@ -37,7 +37,7 @@ import numpy as np
 from ..api.chaos import sync_point
 from ..models import lm
 from ..models.config import ModelConfig
-from ..obs import counter, emit, histogram
+from ..obs import counter, emit, histogram, span
 from .kvcache import KVCacheManager
 
 __all__ = ["ServeEngine", "Request", "ServeError", "EmptyPromptError",
@@ -261,12 +261,37 @@ class ServeEngine:
 
     # -- one tick ----------------------------------------------------------
     def step(self) -> bool:
-        """One engine tick; returns False when there was nothing to do."""
+        """One engine tick; returns False when there was nothing to do.
+
+        Under an installed tracer the tick is a ``serve.tick`` profiler
+        span tiled by ``serve.schedule`` (admission, the chunk plan, the
+        feed's upload and the step's dispatch), ``serve.fetch`` (the
+        wait for the step and the logits' copy to the host) and
+        ``serve.sample`` (sampling, bookkeeping, release)."""
+        with span("serve.tick", tick=self.steps):
+            with span("serve.schedule"):
+                planned = self._schedule()
+            if planned is None:
+                return False
+            slots_live, adv, logits = planned
+            with span("serve.fetch", chunk=logits.shape[1],
+                      live=len(slots_live)):
+                logits_np = np.asarray(logits, np.float32)
+                if self.cfg.frontend == "audio":
+                    logits_np = logits_np[:, :, 0]   # sample codebook 0
+            with span("serve.sample"):
+                self._finish(slots_live, adv, logits_np)
+            return True
+
+    def _schedule(self):
+        """Admit, plan the chunk, upload the feed and dispatch the step.
+        Returns (slots fed, tokens per slot, the logits on the device),
+        or None when no slot is fed."""
         sync_point("serve.step", step=self.steps)
         self._admit()
         slots_live = [i for i, r in enumerate(self.active) if r is not None]
         if not slots_live:
-            return False
+            return None
         self.steps += 1
         self._c_steps.inc()
 
@@ -286,7 +311,7 @@ class ServeEngine:
             adv[i] = want
         slots_live = [i for i in slots_live if adv[i] > 0]
         if not slots_live:
-            return False
+            return None
 
         C = 1 if int(adv.max()) <= 1 else self.prefill_chunk
         feed = np.zeros((self.slots, C), np.int32)
@@ -314,10 +339,12 @@ class ServeEngine:
             self.params, arr, self.kv.cache, jnp.asarray(self.kv.table),
             jnp.asarray(self.kv.pos), jnp.asarray(adv),
             jnp.asarray(zb), jnp.asarray(rs))
-        logits_np = np.asarray(logits, np.float32)
-        if self.cfg.frontend == "audio":
-            logits_np = logits_np[:, :, 0]   # sample codebook 0
+        return slots_live, adv, logits
 
+    def _finish(self, slots_live: List[int], adv: np.ndarray,
+                logits_np: np.ndarray) -> None:
+        """Advance the fed slots' clocks, sample the slots whose prompt
+        is in, and release the requests that are done."""
         now = self.clock()
         for i in slots_live:
             r = self.active[i]
@@ -343,7 +370,6 @@ class ServeEngine:
                 self.kv.release(i)
                 self.active[i] = None
                 sync_point("serve.complete", slot=i, uid=r.uid)
-        return True
 
     def _sample(self, logits: np.ndarray, r: Request) -> int:
         if r.temperature <= 0:

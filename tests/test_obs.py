@@ -33,7 +33,7 @@ from repro.obs import (DEFAULT_BUCKETS, MAX_LABEL_SETS, MetricError,
                        MetricsRegistry, NULL_CELL, Span, Tracer, active,
                        catalog, chrome_trace, counter, dump_artifacts, gauge,
                        histogram, install_tracer, installed, installed_tracer,
-                       quantile, spans_from_store, validate_spans)
+                       quantile, span, spans_from_store, validate_spans)
 from repro.obs import registry as obs_registry
 
 from chaos import run_stress
@@ -309,6 +309,33 @@ class TestTracerUnits:
         with installed_tracer(tr):
             emit("Request", "x", "queued")
         assert len(tr.events()) == 1
+
+    def test_span_without_tracer_opens_no_annotation(self, monkeypatch):
+        import jax.profiler
+        opened = []
+
+        class Counting:
+            def __init__(self, name, **stats):
+                opened.append((name, stats))
+
+            def __enter__(self):
+                return self
+
+            def __exit__(self, *exc):
+                return False
+
+        monkeypatch.setattr(jax.profiler, "TraceAnnotation", Counting)
+        install_tracer(None)
+        first = span("serve.tick", tick=0)
+        with first:
+            with span("serve.fetch", chunk=16, live=3):
+                pass
+        assert opened == []
+        assert span("serve.sample") is first      # one shared no-op context
+        with installed_tracer(Tracer()):
+            with span("serve.fetch", chunk=16, live=3):
+                pass
+        assert opened == [("serve.fetch", {"chunk": 16, "live": 3})]
 
     def test_chrome_trace_structure(self):
         roots = [Span("ResourceClaim", "c1", "ResourceClaim/c1#cycle0",

@@ -491,3 +491,89 @@ class TestBreachRelativeCeilings:
                              slo={"p95_latency_ms": 50.0})
         v = CanaryController._breach(spec, {"p95_latency_ms": 60.0}, {})
         assert v and v["metric"] == "p95_latency_ms"
+
+
+# ---------------------------------------------------------------------------
+# profiler spans in the tick, named scopes in the step
+# ---------------------------------------------------------------------------
+
+SCOPES = ("cache", "attention", "ssd", "mlp", "head")
+
+
+def step_hlo(eng, C):
+    """Optimized HLO text of the engine's step program for chunk C."""
+    import jax.numpy as jnp
+    kv = eng.kv
+    return eng._step.lower(
+        eng.params, jnp.zeros((eng.slots, C), jnp.int32), kv.cache,
+        jnp.asarray(kv.table), jnp.asarray(kv.pos),
+        jnp.zeros((eng.slots,), jnp.int32),
+        jnp.full((eng.slots * kv.blocks_per_slot,), kv.num_blocks, jnp.int32),
+        jnp.zeros((eng.slots,), bool)).compile().as_text()
+
+
+class TestTickSpans:
+    def test_traced_ticks_are_tiled_by_their_phases(self, cfg, params,
+                                                    tmp_path):
+        import glob
+        import os
+
+        from jax.profiler import ProfileData
+
+        from repro.obs import Tracer, installed_tracer
+
+        eng = make_engine(cfg, params)
+        eng.submit(list(range(1, 12)), max_new_tokens=3)
+        eng.submit([5, 6], max_new_tokens=4)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            with installed_tracer(Tracer()):
+                done = eng.run()
+        finally:
+            jax.profiler.stop_trace()
+        assert [r.done for r in done] == [True, True]
+        (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True)
+        evs = sorted((e for p in ProfileData.from_file(path).planes
+                      if p.name == "/host:CPU" for line in p.lines
+                      for e in line.events if e.name.startswith("serve.")),
+                     key=lambda e: (e.start_ns, -e.duration_ns))
+        ticks = [e for e in evs if e.name == "serve.tick"]
+        assert len(ticks) == eng.steps
+        assert [int(dict(t.stats)["tick"]) for t in ticks] == list(range(eng.steps))
+        covered = spanned = 0.0
+        chunks = []
+        for t in ticks:
+            kids = [e for e in evs if e.name != "serve.tick"
+                    and t.start_ns <= e.start_ns and e.end_ns <= t.end_ns]
+            assert [k.name for k in kids] == ["serve.schedule", "serve.fetch",
+                                              "serve.sample"]
+            bounds = [t.start_ns] + [x for k in kids
+                                     for x in (k.start_ns, k.end_ns)] + [t.end_ns]
+            assert bounds == sorted(bounds)          # in order, no overlap
+            covered += sum(k.duration_ns for k in kids)
+            spanned += t.duration_ns
+            stats = dict(kids[1].stats)
+            chunks.append(int(stats["chunk"]))
+            assert 1 <= int(stats["live"]) <= eng.slots
+        assert covered >= 0.9 * spanned
+        # 11 prompt tokens in chunks of 4 beside a 2-token prompt, then decode
+        assert chunks[:3] == [4, 4, 4] and set(chunks[3:]) == {1}
+
+
+class TestStepScopes:
+    @pytest.mark.parametrize("name,scopes", [
+        ("h2o-danube-1.8b", {"cache", "attention", "mlp", "head"}),
+        ("mamba2-780m", {"cache", "ssd", "head"}),
+        ("hymba-1.5b", {"cache", "attention", "ssd", "mlp", "head"}),
+    ])
+    def test_each_family_carries_its_scopes(self, name, scopes):
+        import re
+        c = f32(name)
+        eng = make_engine(c, lm.init_params(c, jax.random.PRNGKey(0)))
+        for C in (1, eng.prefill_chunk):
+            found = {part for path in re.findall(r'op_name="([^"]*)"',
+                                                 step_hlo(eng, C))
+                     for part in path.split("/") if part in SCOPES}
+            assert found == scopes, (name, C)
+
