@@ -86,6 +86,8 @@ _SRV_STEPS = counter("plane_serve_steps_total",
                      "engine ticks that fed the model")
 _SRV_QUEUE_TIME = histogram("plane_serve_queue_time_seconds",
                             "submit -> slot admission wait")
+_SRV_FETCH_BYTES = counter("plane_serve_fetch_bytes_total",
+                           "bytes of sampled logits rows copied to the host")
 
 # Engine names for trace emits ("eng-0:r3"): stable within a process.
 _ENGINE_IDS = itertools.count()
@@ -104,6 +106,30 @@ def _jitted_step(cfg: ModelConfig):
 
         fn = jax.jit(serve_decode_chunk, donate_argnums=(2,))
         _JIT_STEPS[cfg] = fn
+    return fn
+
+
+# ...and one program per ModelConfig that picks the rows the host samples.
+_JIT_ROWS: Dict[Any, Any] = {}
+
+
+def _jitted_rows(cfg: ModelConfig):
+    """``logits[b, max(adv[b] - 1, 0)]`` for every slot ``b`` (codebook 0
+    where the logits carry a codebook axis), widened to float32: the one
+    row per slot that sampling reads, so the host copies (slots, V) and
+    not the step's whole (slots, C, V) block. Its shapes depend only on
+    the tick's. Widened here, not on the host: float32 rows copy faster
+    than bf16 rows widened on the host, on a v5e (PERF.md)."""
+    fn = _JIT_ROWS.get(cfg)
+    if fn is None:
+        def serve_sampled_rows(logits, adv):
+            rows = logits[jnp.arange(logits.shape[0]), jnp.maximum(adv - 1, 0)]
+            if rows.ndim == 3:
+                rows = rows[:, 0]
+            return rows.astype(jnp.float32)
+
+        fn = jax.jit(serve_sampled_rows)
+        _JIT_ROWS[cfg] = fn
     return fn
 
 
@@ -177,6 +203,7 @@ class ServeEngine:
                                  block_size=block_size,
                                  num_blocks=num_blocks)
         self._step = _jitted_step(cfg)
+        self._rows = _jitted_rows(cfg)
         self.active: List[Optional[Request]] = [None] * batch_slots
         self._fed: List[int] = [0] * batch_slots   # prompt tokens fed so far
         self.pending: List[Request] = []
@@ -189,6 +216,7 @@ class ServeEngine:
         self._c_completed = _SRV_COMPLETED.cell()
         self._c_failed = _SRV_FAILED.cell()
         self._c_steps = _SRV_STEPS.cell()
+        self._c_fetch_bytes = _SRV_FETCH_BYTES.cell()
         self._h_queue_time = _SRV_QUEUE_TIME.cell()
 
     def _rname(self, r: Request) -> str:
@@ -265,28 +293,29 @@ class ServeEngine:
 
         Under an installed tracer the tick is a ``serve.tick`` profiler
         span tiled by ``serve.schedule`` (admission, the chunk plan, the
-        feed's upload and the step's dispatch), ``serve.fetch`` (the
-        wait for the step and the logits' copy to the host) and
-        ``serve.sample`` (sampling, bookkeeping, release)."""
+        feed's upload, the dispatch of the step and of its sampled
+        rows), ``serve.fetch`` (the wait for both and the rows' copy to
+        the host) and ``serve.sample`` (sampling, bookkeeping,
+        release)."""
         with span("serve.tick", tick=self.steps):
             with span("serve.schedule"):
                 planned = self._schedule()
             if planned is None:
                 return False
-            slots_live, adv, logits = planned
-            with span("serve.fetch", chunk=logits.shape[1],
-                      live=len(slots_live)):
-                logits_np = np.asarray(logits, np.float32)
-                if self.cfg.frontend == "audio":
-                    logits_np = logits_np[:, :, 0]   # sample codebook 0
+            slots_live, adv, chunk, rows = planned
+            with span("serve.fetch", chunk=chunk, live=len(slots_live),
+                      bytes=rows.nbytes):
+                rows_np = np.asarray(rows)
+            self._c_fetch_bytes.inc(rows_np.nbytes)
             with span("serve.sample"):
-                self._finish(slots_live, adv, logits_np)
+                self._finish(slots_live, adv, rows_np)
             return True
 
     def _schedule(self):
-        """Admit, plan the chunk, upload the feed and dispatch the step.
-        Returns (slots fed, tokens per slot, the logits on the device),
-        or None when no slot is fed."""
+        """Admit, plan the chunk, upload the feed and dispatch the step
+        and its sampled rows. Returns (slots fed, tokens per slot, the
+        chunk width, the rows on the device), or None when no slot is
+        fed."""
         sync_point("serve.step", step=self.steps)
         self._admit()
         slots_live = [i for i, r in enumerate(self.active) if r is not None]
@@ -335,16 +364,18 @@ class ServeEngine:
         rs = self.kv.take_reset_slots()
         if rs is None:
             rs = np.zeros((self.slots,), bool)
+        adv_dev = jnp.asarray(adv)
         logits, self.kv.cache = self._step(
             self.params, arr, self.kv.cache, jnp.asarray(self.kv.table),
-            jnp.asarray(self.kv.pos), jnp.asarray(adv),
+            jnp.asarray(self.kv.pos), adv_dev,
             jnp.asarray(zb), jnp.asarray(rs))
-        return slots_live, adv, logits
+        return slots_live, adv, C, self._rows(logits, adv_dev)
 
     def _finish(self, slots_live: List[int], adv: np.ndarray,
-                logits_np: np.ndarray) -> None:
+                rows_np: np.ndarray) -> None:
         """Advance the fed slots' clocks, sample the slots whose prompt
-        is in, and release the requests that are done."""
+        is in from their rows, and release the requests that are
+        done."""
         now = self.clock()
         for i in slots_live:
             r = self.active[i]
@@ -354,7 +385,7 @@ class ServeEngine:
                 self._fed[i] += n
                 if self._fed[i] < len(r.prompt):
                     continue                 # more prompt chunks to go
-            nxt = self._sample(logits_np[i, n - 1], r)
+            nxt = self._sample(rows_np[i], r)
             if r.t_first_token is None:
                 r.t_first_token = now
                 r.state = STATUS_DECODE

@@ -577,3 +577,99 @@ class TestStepScopes:
                      for part in path.split("/") if part in SCOPES}
             assert found == scopes, (name, C)
 
+
+# ---------------------------------------------------------------------------
+# the host copies one sampled logits row per slot, not the whole block
+# ---------------------------------------------------------------------------
+
+class FullBlockFetch(ServeEngine):
+    """The whole (slots, C, V) block copied and widened on the host, each
+    slot's row picked there: the fetch the sampled rows replace."""
+
+    def __init__(self, *a, **kw):
+        super().__init__(*a, **kw)
+        self._rows = self._full_block
+
+    @staticmethod
+    def _full_block(logits, adv):
+        block = np.asarray(logits, np.float32)
+        if block.ndim == 4:
+            block = block[:, :, 0]              # sample codebook 0
+        return block[np.arange(block.shape[0]), np.maximum(np.asarray(adv) - 1, 0)]
+
+
+def staggered(eng):
+    """Joins that put prompt chunks beside decode, one request sampled at
+    a temperature; returns each request's tokens."""
+    rs = [eng.submit(list(range(3, 14)), max_new_tokens=6)]
+    eng.step()
+    rs.append(eng.submit([8, 1, 4, 4, 2, 6], max_new_tokens=7,
+                         temperature=0.8))
+    eng.step()
+    eng.step()
+    rs.append(eng.submit([7, 3, 9], max_new_tokens=5))
+    eng.run()
+    assert [r.done for r in rs] == [True] * 3
+    return [r.generated for r in rs]
+
+
+class TestSampledRows:
+    @pytest.mark.parametrize("name", ["h2o-danube-1.8b", "mamba2-780m",
+                                      "musicgen-medium"])
+    def test_served_tokens_equal_the_full_block_fetch(self, name):
+        c = smoke_config(name)                  # bf16, as served
+        p = lm.init_params(c, jax.random.PRNGKey(1))
+        out = [staggered(cls(c, p, batch_slots=3, max_len=64,
+                             prefill_chunk=4, seed=11))
+               for cls in (FullBlockFetch, ServeEngine)]
+        assert out[0] == out[1]
+
+    def test_one_row_per_slot_per_tick(self, params, tmp_path):
+        import glob
+        import os
+
+        from jax.profiler import ProfileData
+
+        from repro.obs import (MetricsRegistry, Tracer, installed,
+                               installed_tracer)
+
+        c = f32("yi-34b").replace(name="sampled-rows-shapes")
+        with installed(MetricsRegistry()) as reg:
+            eng = make_engine(c, params, batch_slots=3)
+
+            def fetched():
+                return sum(s["value"] for s in reg.collect()
+                           if s["name"] == "plane_serve_fetch_bytes_total")
+
+            row_bytes = eng.slots * c.vocab_size * 4          # float32 rows
+            advanced = []
+
+            def tick():
+                before = fetched()
+                eng.step()
+                advanced.append(fetched() - before)
+
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                with installed_tracer(Tracer()):
+                    eng.submit(list(range(1, 12)), max_new_tokens=3)
+                    tick()
+                    eng.submit([5, 6], max_new_tokens=8)
+                    tick()
+                    eng.submit([9, 9, 9, 9, 9], max_new_tokens=2)
+                    while eng.has_work():
+                        tick()
+            finally:
+                jax.profiler.stop_trace()
+        assert advanced == [row_bytes] * eng.steps
+        (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True)
+        fetches = [dict(e.stats) for p in ProfileData.from_file(path).planes
+                   if p.name == "/host:CPU" for line in p.lines
+                   for e in line.events if e.name == "serve.fetch"]
+        assert len(fetches) == eng.steps
+        assert {int(s["bytes"]) for s in fetches} == {row_bytes}
+        assert len({int(s["live"]) for s in fetches}) > 1
+        chunks = {int(s["chunk"]) for s in fetches}
+        assert chunks == {1, eng.prefill_chunk}
+        assert eng._rows._cache_size() == len(chunks)
