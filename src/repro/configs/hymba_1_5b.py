@@ -12,7 +12,6 @@ from ..models.config import ModelConfig
 CONFIG = ModelConfig(
     name="hymba-1.5b",
     family="hybrid",
-    hybrid=True,
     num_layers=32,
     d_model=1600,
     num_heads=25,

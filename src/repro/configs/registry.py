@@ -6,9 +6,9 @@ import dataclasses
 from typing import Dict
 
 from ..models.config import ModelConfig
-from . import (arctic_480b, grok_1_314b, h2o_danube_1_8b, hymba_1_5b,
-               internvl2_1b, mamba2_780m, musicgen_medium, phi3_medium_14b,
-               qwen1_5_110b, yi_34b)
+from . import (arctic_480b, granite_4_0_h_micro, grok_1_314b,
+               h2o_danube_1_8b, hymba_1_5b, internvl2_1b, mamba2_780m,
+               musicgen_medium, phi3_medium_14b, qwen1_5_110b, yi_34b)
 
 ARCHS: Dict[str, ModelConfig] = {
     "arctic-480b": arctic_480b.CONFIG,
@@ -21,6 +21,7 @@ ARCHS: Dict[str, ModelConfig] = {
     "hymba-1.5b": hymba_1_5b.CONFIG,
     "internvl2-1b": internvl2_1b.CONFIG,
     "musicgen-medium": musicgen_medium.CONFIG,
+    "granite-4.0-h-micro": granite_4_0_h_micro.CONFIG,
 }
 
 
@@ -59,4 +60,8 @@ def smoke_config(name: str) -> ModelConfig:
         kw.update(vit_dim=32, num_patches=8)
     if cfg.frontend == "audio":
         kw.update(num_codebooks=2, vocab_size=64)
+    if cfg.layer_types:
+        # every kind of the pattern, attention neither first nor last
+        kw.update(num_layers=4,
+                  layer_types=("mamba", "mamba", "attention", "mamba"))
     return dataclasses.replace(cfg, **kw)

@@ -250,8 +250,8 @@ def lower_cell(arch: str, shape_name: str, mesh=None, multi_pod: bool = False,
     record["compile_s"] = main["compile_s"]
     record["memory"] = main["memory"]
 
-    # pass 2: cost fidelity
-    if exact_cost:
+    # pass 2: cost fidelity (a layer pattern is no one layer times depth)
+    if exact_cost or cfg.layer_types:
         full = _compile_once(cfg, shape, mesh, rules, unroll=True,
                              donate=donate, step_cfg=step_cfg)
         for k in ("flops", "hlo_bytes", "collectives", "collectives_by_axis"):

@@ -1,20 +1,39 @@
-"""Unified model configuration covering all assigned architecture families."""
+"""Unified model configuration covering all assigned architecture families.
+
+A model is a stack of layers, each of one *kind*; the kind says which
+mixers the layer holds (``LAYER_MIXERS``): attention, the Mamba-2 SSD
+mixer, or both side by side (hymba). Every layer outside the ``ssm``
+family also holds a norm and an MLP (or MoE) after its mixer.
+``layer_types`` spells the kind of each layer out (granite-4.0-h: a few
+attention layers among Mamba-2 ones); left empty, every layer is of the
+family's one kind.
+"""
 
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
 import jax.numpy as jnp
 
-__all__ = ["ModelConfig"]
+__all__ = ["ModelConfig", "LAYER_MIXERS"]
+
+# The mixers a layer of each kind holds, in the order they run.
+LAYER_MIXERS = {
+    "attention": ("attn",),
+    "mamba": ("ssd",),
+    "parallel": ("attn", "ssd"),     # hymba: both on one input, averaged
+}
 
 
 @dataclass(frozen=True)
 class ModelConfig:
     name: str = "model"
     family: str = "dense"  # dense | moe | ssm | hybrid | vlm | audio
+    # one LAYER_MIXERS kind per layer; () -> the family's kind everywhere
+    layer_types: Tuple[str, ...] = ()
 
     # backbone
     num_layers: int = 2
@@ -27,6 +46,7 @@ class ModelConfig:
     act: str = "swiglu"          # swiglu | geglu | gelu
     norm_eps: float = 1e-6
     rope_theta: float = 10_000.0
+    position_embedding: str = "rope"   # rope | nope (no positional signal)
     qkv_bias: bool = False       # qwen1.5 style
     sliding_window: int = 0      # 0 = full attention
     tie_embeddings: bool = False
@@ -47,8 +67,11 @@ class ModelConfig:
     ssm_chunk: int = 256
     conv_kernel: int = 4
 
-    # hybrid (hymba): attention and SSM heads in parallel within a block
-    hybrid: bool = False
+    # granite's muP multipliers; the defaults leave a model as it is
+    embedding_multiplier: float = 1.0  # scales the token embeddings
+    residual_multiplier: float = 1.0   # scales both residual branches
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(head_dim)
+    logits_scaling: float = 1.0        # the logits are divided by it
 
     # modality frontends (STUBS: precomputed embeddings per assignment)
     frontend: str = "none"       # none | vision | audio
@@ -60,10 +83,73 @@ class ModelConfig:
     param_dtype: str = "bfloat16"
     compute_dtype: str = "bfloat16"
 
+    def __post_init__(self):
+        object.__setattr__(self, "layer_types", tuple(self.layer_types))
+        if self.layer_types:
+            if len(self.layer_types) != self.num_layers:
+                raise ValueError(f"{self.name}: {len(self.layer_types)} "
+                                 f"layer_types for {self.num_layers} layers")
+            unknown = set(self.layer_types) - set(LAYER_MIXERS)
+            if unknown:
+                raise ValueError(f"{self.name}: unknown layer kinds {unknown}")
+        for mixer in ("attn", "ssd"):
+            owners = [k for k in self.layer_kinds if mixer in LAYER_MIXERS[k]]
+            if len(owners) > 1:
+                # each cache is indexed by a layer's rank within its kind
+                raise ValueError(f"{self.name}: kinds {owners} would share "
+                                 f"the {mixer} cache")
+
+    # ---------------------------------------------------------- layer pattern
+    @property
+    def layer_pattern(self) -> Tuple[str, ...]:
+        """The kind of each layer, first to last."""
+        if self.layer_types:
+            return self.layer_types
+        kind = {"ssm": "mamba", "hybrid": "parallel"}.get(self.family,
+                                                           "attention")
+        return (kind,) * self.num_layers
+
+    @property
+    def layer_kinds(self) -> Tuple[str, ...]:
+        """The distinct kinds, in the order they first appear."""
+        return tuple(dict.fromkeys(self.layer_pattern))
+
+    def kind_count(self, kind: str) -> int:
+        return self.layer_pattern.count(kind)
+
+    @property
+    def layer_runs(self) -> Tuple[Tuple[str, int, int], ...]:
+        """(kind, rank of the run's first layer within its kind, length)
+        of each run of consecutive layers of one kind."""
+        runs = []
+        seen: dict = {}
+        for kind in self.layer_pattern:
+            if runs and runs[-1][0] == kind:
+                runs[-1][2] += 1
+            else:
+                runs.append([kind, seen.get(kind, 0), 1])
+            seen[kind] = seen.get(kind, 0) + 1
+        return tuple(tuple(r) for r in runs)
+
+    def mixer_layers(self, mixer: str) -> int:
+        """Layers holding ``mixer`` ("attn" or "ssd"): the depth of its
+        cache."""
+        return sum(mixer in LAYER_MIXERS[k] for k in self.layer_pattern)
+
+    @property
+    def has_mlp(self) -> bool:
+        """Every layer outside the attention-free ``ssm`` stacks."""
+        return self.family != "ssm"
+
+    @property
+    def attention_scale(self) -> float:
+        return self.attention_multiplier or 1.0 / math.sqrt(
+            self.resolved_head_dim)
+
     # long-context capability flag (decides long_500k applicability)
     @property
     def subquadratic(self) -> bool:
-        return self.family in ("ssm", "hybrid") or self.sliding_window > 0
+        return self.sliding_window > 0 or self.mixer_layers("attn") == 0
 
     @property
     def resolved_head_dim(self) -> int:
@@ -118,15 +204,16 @@ class ModelConfig:
         extra = 2 * h + di                              # A, dt_bias, D... approx
         return in_proj + conv + out + extra
 
-    def layer_param_count(self, active_only: bool = False) -> int:
-        """Parameters in one decoder layer (norms ignored: O(d))."""
+    def layer_param_count(self, kind: str, active_only: bool = False) -> int:
+        """Parameters in one decoder layer of ``kind``."""
+        mixers = LAYER_MIXERS[kind]
         n = 2 * self.d_model  # the two norms, for honesty
-        if self.family == "ssm":
-            return n + self._ssm_params()
-        if self.hybrid:
-            n += self._attn_params() + self._ssm_params() + self._ffn_params(self.d_ff)
+        if "attn" in mixers:
+            n += self._attn_params()
+        if "ssd" in mixers:
+            n += self._ssm_params()
+        if not self.has_mlp:
             return n
-        n += self._attn_params()
         if self.num_experts > 0:
             e = self.top_k if active_only else self.num_experts
             n += e * self._ffn_params(self.expert_d_ff)
@@ -147,7 +234,9 @@ class ModelConfig:
         fe = 0
         if self.frontend == "vision":
             fe = self.vit_dim * self.d_model + 2 * self.d_model * self.d_model
-        return emb + head + fe + self.num_layers * self.layer_param_count(active_only)
+        layers = sum(self.kind_count(k) * self.layer_param_count(k, active_only)
+                     for k in self.layer_kinds)
+        return emb + head + fe + layers
 
     def active_param_count(self) -> int:
         return self.param_count(active_only=True)

@@ -81,7 +81,7 @@ def apply_rope(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Attention (GQA, causal, optional sliding window)
+# Attention (GQA, causal, optional sliding window; RoPE or none)
 # ---------------------------------------------------------------------------
 
 
@@ -116,9 +116,10 @@ def _qkv(cfg: ModelConfig, p: Params, x: jax.Array,
         q = q + p["bq"].astype(cdt)
         k = k + p["bk"].astype(cdt)
         v = v + p["bv"].astype(cdt)
-    cos, sin = rope_table(positions, cfg.resolved_head_dim, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.position_embedding == "rope":
+        cos, sin = rope_table(positions, cfg.resolved_head_dim, cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     q = constrain(q, "batch", "seq", "act_heads", None)
     k = constrain(k, "batch", "seq", "act_kv", None)
     v = constrain(v, "batch", "seq", "act_kv", None)
@@ -145,7 +146,7 @@ def _attend_dense(cfg: ModelConfig, q, k, v, q_pos, k_pos) -> jax.Array:
     G = H // K
     qg = q.reshape(B, Sq, K, G, hd)
     qg = constrain(qg, "batch", "seq", "act_kv", None, None)
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attention_scale
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, k).astype(jnp.float32) * scale
     scores = constrain(scores, "batch", "act_kv", None, "seq", None)
     mask = _mask(q_pos, k_pos, cfg.sliding_window)
@@ -166,7 +167,7 @@ def _attend_blockwise(cfg: ModelConfig, q, k, v, q_pos, k_pos) -> jax.Array:
     q = constrain(q, "batch", None, "act_heads", None)
     k = constrain(k, "batch", None, "act_kv", None)
     v = constrain(v, "batch", None, "act_kv", None)
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attention_scale
     nq = -(-S // Q_BLOCK)
     nk = -(-S // KV_BLOCK)
     pad_q = nq * Q_BLOCK - S
@@ -219,6 +220,10 @@ def attention_apply(cfg: ModelConfig, p: Params, x: jax.Array,
     pos = positions if positions.ndim == 1 else positions[0]
     if attention_impl == "kernel":
         from ..kernels.flash_attention.ops import flash_attention
+        if cfg.attention_multiplier:
+            # the kernel scales by 1/sqrt(hd): fold the model's scale into q
+            q = q * (cfg.attention_multiplier
+                     * math.sqrt(cfg.resolved_head_dim))
         out = flash_attention(q, k, v, causal=True, window=cfg.sliding_window)
     elif attention_impl == "dense" or (attention_impl == "auto"
                                        and S <= BLOCKWISE_THRESHOLD):
@@ -256,9 +261,11 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
         q = q + p["bq"].astype(cdt)
         k = k + p["bk"].astype(cdt)
         v = v + p["bv"].astype(cdt)
-    cos, sin = rope_table(pos[:, None], cfg.resolved_head_dim, cfg.rope_theta)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.position_embedding == "rope":
+        cos, sin = rope_table(pos[:, None], cfg.resolved_head_dim,
+                              cfg.rope_theta)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
     slot = pos % Scache if cfg.sliding_window > 0 else pos
     rows = jnp.arange(B)
     ck = cache["k"].at[rows, slot].set(k[:, 0].astype(cache["k"].dtype))
@@ -270,7 +277,7 @@ def attention_decode(cfg: ModelConfig, p: Params, x: jax.Array,
     hd = cfg.resolved_head_dim
     qg = q.reshape(B, 1, K, H // K, hd)
     scores = jnp.einsum("bqkgh,bskh->bkgqs", qg, ck.astype(cdt)
-                        ).astype(jnp.float32) / math.sqrt(hd)
+                        ).astype(jnp.float32) * cfg.attention_scale
     idx = jnp.arange(Scache)
     if cfg.sliding_window > 0:
         valid = idx[None, :] < jnp.minimum(pos + 1, Scache)[:, None]
@@ -310,7 +317,7 @@ def attention_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array,
     S = nb * bs
     H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     G = H // K
-    scale = 1.0 / math.sqrt(hd)
+    scale = cfg.attention_scale
 
     qpos = pos[:, None] + jnp.arange(C, dtype=pos.dtype)[None, :]    # (B,C)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cdt))
@@ -320,9 +327,10 @@ def attention_decode_paged(cfg: ModelConfig, p: Params, x: jax.Array,
         q = q + p["bq"].astype(cdt)
         k = k + p["bk"].astype(cdt)
         v = v + p["bv"].astype(cdt)
-    cos, sin = rope_table(qpos, hd, cfg.rope_theta)                  # (B,C,half)
-    q = apply_rope(q, cos, sin)
-    k = apply_rope(k, cos, sin)
+    if cfg.position_embedding == "rope":
+        cos, sin = rope_table(qpos, hd, cfg.rope_theta)              # (B,C,half)
+        q = apply_rope(q, cos, sin)
+        k = apply_rope(k, cos, sin)
 
     # resident keys, gathered logical-contiguous through the block table
     ck = kv["k"][block_table].reshape(B, S, K, hd).astype(cdt)

@@ -1,15 +1,22 @@
 """Full language models: params, forward, loss, prefill, decode.
 
-One assembly covers all ten assigned architectures; the per-layer body
-dispatches on config.family:
+One assembly covers every registry architecture. Each layer is of one
+kind (``ModelConfig.layer_pattern``), and its body follows the mixers the
+kind holds (``LAYER_MIXERS``), read off the layer's params:
 
-  dense   : x += attn(n1(x));  x += mlp(n2(x))
-  moe     : x += attn(n1(x));  x += moe(n2(x))   [+ dense residual inside]
-  ssm     : x += ssd(n1(x))                       (attention-free)
-  hybrid  : x += (attn(n1(x)) + ssd(n1(x)))/2;  x += mlp(n2(x))  (hymba)
+  attention : x += attn(n1(x));  x += mlp(n2(x))    [moe(n2(x)) for MoE]
+  mamba     : x += ssd(n1(x))    [+ x += mlp(n2(x)) outside the ssm family]
+  parallel  : x += (attn(n1(x)) + ssd(n1(x)))/2;  x += mlp(n2(x))  (hymba)
 
-Layers are scanned (stacked params) so HLO size is depth-independent —
-required to compile 80-layer models against 512 devices in a dry run.
+granite-4.0-h also scales both residual branches (``residual_multiplier``),
+the embeddings and the logits.
+
+Layers are scanned over params stacked per kind, so HLO size is
+depth-independent — required to compile 80-layer models against 512
+devices in a dry run. A stack of one kind is one scan, as it always was;
+a pattern of kinds is one scan per run of consecutive same-kind layers
+(:func:`scan_layers`), and each cache a kind owns (the K/V pool, the SSD
+state) is indexed by the layer's rank within that kind.
 
 Frontends (assignment: STUBS taking precomputed embeddings):
   vision (internvl2): patch embeddings (B, P, vit_dim) -> MLP projector ->
@@ -28,7 +35,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..parallel.sharding import constrain
-from .config import ModelConfig
+from .config import LAYER_MIXERS, ModelConfig
 from .layers import (attention_apply, attention_decode,
                      attention_decode_paged, build_attention, build_mlp,
                      build_moe, build_rmsnorm, build_ssd, init_kv_cache,
@@ -44,14 +51,15 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def build_layer(b: Builder, cfg: ModelConfig) -> Params:
+def build_layer(b: Builder, cfg: ModelConfig, kind: str) -> Params:
+    mixers = LAYER_MIXERS[kind]
     p: Params = {"norm1": build_rmsnorm(b, "norm1", cfg.d_model)}
-    if cfg.family == "ssm":
+    if "attn" in mixers:
+        p["attn"] = build_attention(b, cfg)
+    if "ssd" in mixers:
         p["ssd"] = build_ssd(b, cfg)
+    if not cfg.has_mlp:
         return p
-    p["attn"] = build_attention(b, cfg)
-    if cfg.hybrid:
-        p["ssd"] = build_ssd(b, cfg)
     p["norm2"] = build_rmsnorm(b, "norm2", cfg.d_model)
     if cfg.num_experts > 0:
         p["moe"] = build_moe(b, cfg)
@@ -84,10 +92,27 @@ def build_params(b: Builder, cfg: ModelConfig) -> Params:
                                        ("vit", "embed"), normal_init(0.02))
                 p["proj_hidden"] = b.param("hidden", (cfg.d_model, cfg.d_model),
                                            ("embed", "act_embed"), normal_init(0.02))
-        with b.scope("layers"), b.stacked(cfg.num_layers):
-            p["layers"] = build_layer(b, cfg)
+        with b.scope("layers"):
+            kinds = cfg.layer_kinds
+            if len(kinds) == 1:
+                with b.stacked(cfg.num_layers):
+                    p["layers"] = build_layer(b, cfg, kinds[0])
+            else:
+                p["layers"] = {}
+                for kind in kinds:
+                    with b.scope(kind), b.stacked(cfg.kind_count(kind)):
+                        p["layers"][kind] = build_layer(b, cfg, kind)
         p["final_norm"] = build_rmsnorm(b, "final_norm", cfg.d_model)
     return p
+
+
+def layer_stacks(cfg: ModelConfig, params: Params) -> Dict[str, Params]:
+    """Each layer kind's stacked params: ``params["layers"]`` itself for a
+    stack of one kind, else one entry of it per kind."""
+    kinds = cfg.layer_kinds
+    if len(kinds) == 1:
+        return {kinds[0]: params["layers"]}
+    return params["layers"]
 
 
 def init_params(cfg: ModelConfig, key: jax.Array) -> Params:
@@ -110,24 +135,64 @@ def param_specs(cfg: ModelConfig) -> Params:
 # ---------------------------------------------------------------------------
 
 
+def residual(cfg: ModelConfig, x: jax.Array, ys) -> jax.Array:
+    """``x`` plus the branch: the mean of the layer's mixer outputs
+    ``ys`` (one, or hymba's two), scaled by ``residual_multiplier``."""
+    y = ys[0] if len(ys) == 1 else 0.5 * (ys[0] + ys[1])
+    if cfg.residual_multiplier != 1.0:
+        y = y * cfg.residual_multiplier
+    return x + y
+
+
 def layer_apply(cfg: ModelConfig, lp: Params, x: jax.Array,
                 positions: jax.Array, attention_impl: str = "auto"
                 ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """One layer of whichever kind ``lp`` holds."""
     aux: Dict[str, jax.Array] = {}
     h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
-    if cfg.family == "ssm":
-        return x + ssd_apply(cfg, lp["ssd"], h), aux
-    att = attention_apply(cfg, lp["attn"], h, positions, attention_impl)
-    if cfg.hybrid:
-        att = 0.5 * (att + ssd_apply(cfg, lp["ssd"], h))
-    x = x + att
+    ys = []
+    if "attn" in lp:
+        ys.append(attention_apply(cfg, lp["attn"], h, positions,
+                                  attention_impl))
+    if "ssd" in lp:
+        ys.append(ssd_apply(cfg, lp["ssd"], h))
+    x = residual(cfg, x, ys)
+    if not cfg.has_mlp:
+        return x, aux
     h2 = rmsnorm(lp["norm2"], x, cfg.norm_eps)
     if cfg.num_experts > 0:
         y, moe_aux = moe_apply(cfg, lp["moe"], h2)
         aux.update(moe_aux)
     else:
         y = mlp_apply(cfg, lp["mlp"], h2)
-    return x + y, aux
+    return residual(cfg, x, [y]), aux
+
+
+def scan_layers(cfg: ModelConfig, params: Params, body, carry,
+                unroll: int = 1):
+    """Run ``body(carry, (lp, li)) -> (carry, y)`` over every layer, first
+    to last: one ``lax.scan`` per run of consecutive layers of one kind,
+    over that kind's stacked params. ``li`` is the layer's rank within
+    its kind, which also indexes each cache the kind owns. Returns the
+    carry and each run's stacked ``y``, in order."""
+    stacks = layer_stacks(cfg, params)
+    ys = []
+    for kind, first, n in cfg.layer_runs:
+        stack = stacks[kind]
+        ranks = jnp.arange(first, first + n, dtype=jnp.int32)
+        if n == cfg.kind_count(kind):           # the whole stack: its xs
+            carry, y = lax.scan(body, carry, (stack, ranks),
+                                unroll=min(unroll, n))
+        else:
+            def fetch(c, li, stack=stack):
+                lp = jax.tree.map(
+                    lambda a: lax.dynamic_index_in_dim(a, li, 0,
+                                                       keepdims=False), stack)
+                return body(c, (lp, li))
+
+            carry, y = lax.scan(fetch, carry, ranks, unroll=min(unroll, n))
+        ys.append(y)
+    return carry, ys
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +211,8 @@ def embed_tokens(cfg: ModelConfig, p: Params, batch: Dict[str, jax.Array]
             x = x + jnp.take(p["embed"][c], codes[..., c], axis=0).astype(cdt)
     else:
         x = jnp.take(p["embed"], batch["tokens"], axis=0).astype(cdt)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * cfg.embedding_multiplier
     if cfg.frontend == "vision" and "patch_embeds" in batch:
         pe = batch["patch_embeds"].astype(cdt)                   # (B,P,vit)
         img = jnp.einsum("bpv,vd->bpd", pe, p["proj_in"].astype(cdt))
@@ -164,6 +231,8 @@ def lm_head(cfg: ModelConfig, p: Params, x: jax.Array) -> jax.Array:
         return constrain(logits, "batch", "seq", None, "act_vocab")
     w = p["embed"].T if cfg.tie_embeddings else p["head"]
     logits = jnp.einsum("bsd,dv->bsv", x, w.astype(cdt))
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     return constrain(logits, "batch", "seq", "act_vocab")
 
 
@@ -177,9 +246,9 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
             unroll: int = 1) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     x, positions = embed_tokens(cfg, params, batch)
 
-    def body(carry, lp):
+    def body(carry, scan_in):
         h, aux_acc = carry
-        h, aux = layer_apply(cfg, lp, h, positions, attention_impl)
+        h, aux = layer_apply(cfg, scan_in[0], h, positions, attention_impl)
         for k_, v in aux.items():
             aux_acc = {**aux_acc, k_: aux_acc.get(k_, 0.0) + v}
         return (h, aux_acc), None
@@ -194,8 +263,7 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
         body = jax.checkpoint(
             body, policy=jax.checkpoint_policies.checkpoint_dots_with_no_batch_dims,
             prevent_cse=False)
-    (x, aux), _ = lax.scan(body, (x, aux0), params["layers"],
-                           unroll=min(unroll, cfg.num_layers))
+    (x, aux), _ = scan_layers(cfg, params, body, (x, aux0), unroll)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return lm_head(cfg, params, x), aux
 
@@ -248,40 +316,42 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict[str, Any]:
     admit/recycle slots independently (scalar clocks are still accepted
     by :func:`decode_step` for old callers/checkpoints)."""
     cache: Dict[str, Any] = {"pos": jnp.zeros((batch,), jnp.int32)}
-    L = cfg.num_layers
-    if cfg.family != "ssm":
+    n_attn, n_ssd = cfg.mixer_layers("attn"), cfg.mixer_layers("ssd")
+    if n_attn:
         kv = init_kv_cache(cfg, batch, max_len)
         cache["kv"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (L,) + a.shape), kv)
-    if cfg.family in ("ssm", "hybrid"):
+            lambda a: jnp.broadcast_to(a, (n_attn,) + a.shape), kv)
+    if n_ssd:
         sc = init_ssd_cache(cfg, batch)
         cache["ssd"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (L,) + a.shape), sc)
+            lambda a: jnp.broadcast_to(a, (n_ssd,) + a.shape), sc)
     return cache
 
 
 def init_paged_cache(cfg: ModelConfig, slots: int, num_blocks: int,
                      block_size: int) -> Dict[str, Any]:
-    """Paged decode cache: a physical KV block pool + per-slot SSD state.
+    """Paged decode cache: a physical KV block pool + per-slot SSD state,
+    each as deep as the layers that own it.
 
-    kv k/v are (L, num_blocks, block_size, K, hd) — one pool shared by
-    all slots; block 0 is the reserved always-zero sentinel that empty
-    block-table entries point at. Position clocks and block tables are
-    NOT part of this pytree: the serve-side
-    :class:`repro.serve.kvcache.KVCacheManager` owns them host-side and
-    passes them into :func:`decode_chunk` per tick.
+    kv k/v are (La, num_blocks, block_size, K, hd), La the layers with
+    attention — one pool shared by all slots; block 0 is the reserved
+    always-zero sentinel that empty block-table entries point at. The
+    SSD state is (Ls, slots, ...), Ls the layers with an SSD mixer.
+    Position clocks and block tables are NOT part of this pytree: the
+    serve-side :class:`repro.serve.kvcache.KVCacheManager` owns them
+    host-side and passes them into :func:`decode_chunk` per tick.
     """
     cache: Dict[str, Any] = {}
-    L = cfg.num_layers
-    if cfg.family != "ssm":
+    n_attn, n_ssd = cfg.mixer_layers("attn"), cfg.mixer_layers("ssd")
+    if n_attn:
         hd = cfg.resolved_head_dim
-        shape = (L, num_blocks, block_size, cfg.num_kv_heads, hd)
+        shape = (n_attn, num_blocks, block_size, cfg.num_kv_heads, hd)
         dt = cfg.compute_jnp_dtype()
         cache["kv"] = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    if cfg.family in ("ssm", "hybrid"):
+    if n_ssd:
         sc = init_ssd_cache(cfg, slots)
         cache["ssd"] = jax.tree.map(
-            lambda a: jnp.broadcast_to(a, (L,) + a.shape), sc)
+            lambda a: jnp.broadcast_to(a, (n_ssd,) + a.shape), sc)
     return cache
 
 
@@ -348,40 +418,38 @@ def decode_chunk(cfg: ModelConfig, params: Params, tokens: jax.Array,
     def body(carry, scan_in):
         h, kv_all, ssd_all = carry
         lp, li = scan_in
-        if cfg.family == "ssm":
-            ssd_l = get_layer(ssd_all, li)
-            with scope("ssd"):
+        ys = []
+        if "attn" in lp:
+            kv_l = get_layer(kv_all, li)
+            with scope("attention"):
                 hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
-                y, new_ssd = ssd_decode_chunk(cfg, lp["ssd"], hn, ssd_l, adv)
-            ssd_all = set_layer(ssd_all, new_ssd, li)
-            return (h + y, kv_all, ssd_all), None
-        kv_l = get_layer(kv_all, li)
-        with scope("attention"):
-            hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
-            att, new_kv = attention_decode_paged(cfg, lp["attn"], hn, kv_l,
-                                                 block_table, pos, adv)
-        kv_all = set_layer(kv_all, new_kv, li)
-        if cfg.hybrid:
+                att, new_kv = attention_decode_paged(cfg, lp["attn"], hn, kv_l,
+                                                     block_table, pos, adv)
+            kv_all = set_layer(kv_all, new_kv, li)
+            ys.append(att)
+        if "ssd" in lp:
             ssd_l = get_layer(ssd_all, li)
             with scope("ssd"):
+                if not ys:
+                    hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
                 y2, new_ssd = ssd_decode_chunk(cfg, lp["ssd"], hn, ssd_l, adv)
             ssd_all = set_layer(ssd_all, new_ssd, li)
-            att = 0.5 * (att + y2)
-        h = h + att
+            ys.append(y2)
+        h = residual(cfg, h, ys)
+        if not cfg.has_mlp:
+            return (h, kv_all, ssd_all), None
         with scope("mlp"):
             h2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
             if cfg.num_experts > 0:
                 y, _ = moe_apply(cfg, lp["moe"], h2)
             else:
                 y = mlp_apply(cfg, lp["mlp"], h2)
-        return (h + y, kv_all, ssd_all), None
+        return (residual(cfg, h, [y]), kv_all, ssd_all), None
 
     kv0 = cache.get("kv", jnp.zeros((L, 1)))
     ssd0 = cache.get("ssd", jnp.zeros((L, 1)))
-    (x, new_kv, new_ssd), _ = lax.scan(
-        body, (x, kv0, ssd0),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-        unroll=min(unroll, cfg.num_layers))
+    (x, new_kv, new_ssd), _ = scan_layers(cfg, params, body, (x, kv0, ssd0),
+                                          unroll)
     with scope("head"):
         x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
         logits = lm_head(cfg, params, x)
@@ -423,31 +491,30 @@ def decode_step(cfg: ModelConfig, params: Params, tokens: jax.Array,
         h, kv_all, ssd_all = carry
         lp, li = scan_in
         hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
-        if cfg.family == "ssm":
-            y, new_ssd = ssd_decode(cfg, lp["ssd"], hn, get_layer(ssd_all, li))
-            ssd_all = set_layer(ssd_all, new_ssd, li)
-            return (h + y, kv_all, ssd_all), None
-        att, new_kv = attention_decode(cfg, lp["attn"], hn,
-                                       get_layer(kv_all, li), pos)
-        kv_all = set_layer(kv_all, new_kv, li)
-        if cfg.hybrid:
+        ys = []
+        if "attn" in lp:
+            att, new_kv = attention_decode(cfg, lp["attn"], hn,
+                                           get_layer(kv_all, li), pos)
+            kv_all = set_layer(kv_all, new_kv, li)
+            ys.append(att)
+        if "ssd" in lp:
             y2, new_ssd = ssd_decode(cfg, lp["ssd"], hn, get_layer(ssd_all, li))
             ssd_all = set_layer(ssd_all, new_ssd, li)
-            att = 0.5 * (att + y2)
-        h = h + att
+            ys.append(y2)
+        h = residual(cfg, h, ys)
+        if not cfg.has_mlp:
+            return (h, kv_all, ssd_all), None
         h2 = rmsnorm(lp["norm2"], h, cfg.norm_eps)
         if cfg.num_experts > 0:
             y, _ = moe_apply(cfg, lp["moe"], h2)
         else:
             y = mlp_apply(cfg, lp["mlp"], h2)
-        return (h + y, kv_all, ssd_all), None
+        return (residual(cfg, h, [y]), kv_all, ssd_all), None
 
     kv0 = cache.get("kv", jnp.zeros((L, 1)))
     ssd0 = cache.get("ssd", jnp.zeros((L, 1)))
-    (x, new_kv, new_ssd), _ = lax.scan(
-        body, (x, kv0, ssd0),
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-        unroll=min(unroll, cfg.num_layers))
+    (x, new_kv, new_ssd), _ = scan_layers(cfg, params, body, (x, kv0, ssd0),
+                                          unroll)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = lm_head(cfg, params, x)
     new_cache = dict(cache)
@@ -473,10 +540,11 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
     B, S, _ = x.shape
     max_len = max_len or S
 
-    def body(h, lp):
+    def body(h, scan_in):
+        lp = scan_in[0]
         hn = rmsnorm(lp["norm1"], h, cfg.norm_eps)
         emitted = {}
-        if cfg.family != "ssm":
+        if "attn" in lp:
             from .layers import _qkv
             _, k_, v_ = _qkv(cfg, lp["attn"], hn, positions[None, :])
             if cfg.sliding_window > 0 and S > cfg.sliding_window:
@@ -484,14 +552,17 @@ def prefill(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
                 v_ = v_[:, -cfg.sliding_window:]
             emitted["k"] = k_
             emitted["v"] = v_
-        if cfg.family == "ssm" or cfg.hybrid:
+        if "ssd" in lp:
             _, st = ssd_apply(cfg, lp["ssd"], hn, return_state=True)
             emitted["ssd"] = st
         h, _ = layer_apply(cfg, lp, h, positions, attention_impl)
         return h, emitted
 
-    x, emitted = lax.scan(body, x, params["layers"],
-                          unroll=min(unroll, cfg.num_layers))
+    x, runs = scan_layers(cfg, params, body, x, unroll)
+    # each cache's layers, in order: every cache belongs to one kind
+    emitted = {key: jax.tree.map(lambda *a: jnp.concatenate(a),
+                                 *[r[key] for r in runs if key in r])
+               for key in ("k", "v", "ssd") if any(key in r for r in runs)}
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     logits = lm_head(cfg, params, x[:, -1:])
 

@@ -22,8 +22,13 @@ owns the host-side accounting around it:
   (``zero_blocks``), and recycled slots' SSD recurrence is reset the
   same way (``reset_slots``) — no request can ever observe a
   predecessor's K/V or SSM state, even if a mask were wrong. SSD state
-  is cumulative, so for the ssm/hybrid families the reset is
+  is cumulative, so for every layer with an SSD mixer the reset is
   load-bearing, not just hygiene.
+* **One manager, two kinds of cache, each as deep as its layers.** The
+  K/V pool has one layer per attention layer and the SSD state one per
+  SSD layer (granite-4.0-h: 4 and 36 of 40), so a block, a zero-epoch
+  or a slot reset touches only the layers that own that cache. The
+  bytes each kind holds are gauges (``plane_serve_cache_bytes``).
 """
 
 from __future__ import annotations
@@ -31,6 +36,7 @@ from __future__ import annotations
 import math
 from typing import Any, Dict, List, Optional
 
+import jax
 import numpy as np
 
 from ..models import lm
@@ -45,6 +51,9 @@ _KV_USED = gauge("plane_serve_kv_used_blocks",
                  "KV pool blocks currently reserved by admitted requests")
 _KV_FREE = gauge("plane_serve_kv_free_blocks",
                  "KV pool blocks free for admission")
+_CACHE_BYTES = gauge("plane_serve_cache_bytes",
+                     "device bytes of the paged cache, by kind: the K/V "
+                     "pool (kv) and the SSD state (ssd)", labels=("cache",))
 
 
 class KVCacheManager:
@@ -78,6 +87,9 @@ class KVCacheManager:
         self._g_used = _KV_USED.cell()
         self._g_free = _KV_FREE.cell()
         self._g_free.set(len(self._free))
+        for kind in ("kv", "ssd"):
+            _CACHE_BYTES.cell(cache=kind).set(sum(
+                a.nbytes for a in jax.tree.leaves(self.cache.get(kind, {}))))
 
     # -- accounting --------------------------------------------------------
     def blocks_for(self, tokens: int) -> int:

@@ -8,7 +8,8 @@ from repro.configs.registry import smoke_config
 from repro.models import lm
 
 FAMS = ["yi-34b", "h2o-danube-1.8b", "mamba2-780m", "hymba-1.5b",
-        "musicgen-medium", "arctic-480b", "internvl2-1b"]
+        "musicgen-medium", "arctic-480b", "internvl2-1b",
+        "granite-4.0-h-micro"]
 
 
 def f32(name):

@@ -72,6 +72,7 @@ class TestFullConfigs:
             "hymba-1.5b": (32, 1600, 25, 5, 32001),
             "internvl2-1b": (24, 896, 14, 2, 151655),
             "musicgen-medium": (48, 1536, 24, 24, 2048),
+            "granite-4.0-h-micro": (40, 2048, 32, 8, 100352),
         }[arch]
         assert (cfg.num_layers, cfg.d_model, cfg.num_heads,
                 cfg.num_kv_heads, cfg.vocab_size) == spec
@@ -87,7 +88,8 @@ class TestFullConfigs:
                   "mamba2-780m": (6.5e8, 9.5e8),
                   "hymba-1.5b": (1.1e9, 2.2e9),
                   "internvl2-1b": (6e8, 1.3e9),
-                  "musicgen-medium": (1.3e9, 2.4e9)}
+                  "musicgen-medium": (1.3e9, 2.4e9),
+                  "granite-4.0-h-micro": (2.9e9, 3.5e9)}
         for arch, (lo, hi) in expect.items():
             n = get_config(arch).param_count()
             assert lo <= n <= hi, (arch, n)

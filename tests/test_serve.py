@@ -232,6 +232,90 @@ class TestContaminationRegression:
         assert rb.generated == self.fresh(scfg, sparams, B_PROMPT)
 
 
+class TestLayerPattern:
+    """granite-4.0-h's pattern (smoke size: mamba, mamba, attention,
+    mamba): one manager holds a K/V pool as deep as the attention layers
+    and an SSD state as deep as the Mamba-2 layers, and its hygiene acts
+    on the layers that own each."""
+
+    @pytest.fixture(scope="class")
+    def gcfg(self):
+        return f32("granite-4.0-h-micro")
+
+    @pytest.fixture(scope="class")
+    def gparams(self, gcfg):
+        return lm.init_params(gcfg, jax.random.PRNGKey(0))
+
+    def test_each_cache_is_as_deep_as_its_layers(self, gcfg):
+        from repro.obs import MetricsRegistry, installed
+        with installed(MetricsRegistry()) as reg:
+            m = KVCacheManager(gcfg, 2, 64)
+            held = {s["labels"]["cache"]: s["value"] for s in reg.collect()
+                    if s["name"] == "plane_serve_cache_bytes"}
+        assert gcfg.mixer_layers("attn") == 1 and gcfg.mixer_layers("ssd") == 3
+        assert m.cache["kv"]["k"].shape[0] == 1
+        assert {a.shape[0] for a in jax.tree.leaves(m.cache["ssd"])} == {3}
+        assert held == {kind: sum(a.nbytes for a in jax.tree.leaves(m.cache[kind]))
+                        for kind in ("kv", "ssd")}
+
+    def test_uniform_stacks_hold_one_kind_of_cache(self):
+        from repro.obs import MetricsRegistry, installed
+        with installed(MetricsRegistry()) as reg:
+            KVCacheManager(f32("mamba2-780m"), 2, 64)
+            held = {s["labels"]["cache"]: s["value"] for s in reg.collect()
+                    if s["name"] == "plane_serve_cache_bytes"}
+        assert held["kv"] == 0 and held["ssd"] > 0
+
+    def test_published_pool_holds_four_layers_not_forty(self):
+        from repro.configs.registry import get_config
+        g = get_config("granite-4.0-h-micro")
+        shapes = jax.eval_shape(
+            lambda: lm.init_paged_cache(g, 16, 16 * 8192 // 16 + 1, 16))
+        assert shapes["kv"]["k"].shape == (4, 8193, 16, 8, 64)
+        assert shapes["ssd"]["state"].shape == (36, 16, 64, 128, 64)
+        kv = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(shapes["kv"]))
+        ssd = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(shapes["ssd"]))
+        assert kv == 1_073_872_896 and ssd == 1_223_000_064
+
+    def test_hygiene_acts_on_the_owning_layers(self, gcfg, gparams):
+        """A tick that feeds nothing zero-epochs the queued blocks in
+        every pool layer and resets the marked slot in every SSD layer,
+        and touches nothing else."""
+        import jax.numpy as jnp
+        m = KVCacheManager(gcfg, 2, 64)
+        ones = jax.tree.map(jnp.ones_like, m.cache)
+        zb = np.full((m.slots * m.blocks_per_slot,), m.num_blocks, np.int32)
+        zb[:2] = [2, 5]
+        _, out = lm.decode_chunk(
+            gcfg, gparams, jnp.zeros((2, 1), jnp.int32), ones,
+            jnp.asarray(m.table), jnp.asarray(m.pos),
+            jnp.zeros((2,), jnp.int32), zero_blocks=jnp.asarray(zb),
+            reset_slots=jnp.asarray([False, True]))
+        for a in jax.tree.leaves(out["kv"]):
+            a = np.asarray(a)
+            assert (a[:, [2, 5]] == 0).all()
+            assert (np.delete(a, [2, 5], axis=1) == 1).all()
+        for a in jax.tree.leaves(out["ssd"]):
+            a = np.asarray(a)
+            assert (a[:, 1] == 0).all() and (a[:, 0] == 1).all()
+
+    def test_recycled_slot_equals_fresh_engine(self, gcfg, gparams):
+        def fresh(prompt):
+            eng = ServeEngine(gcfg, gparams, batch_slots=1, max_len=64,
+                              prefill_chunk=4)
+            eng.submit(prompt, max_new_tokens=6)
+            return eng.run()[0].generated
+
+        eng = ServeEngine(gcfg, gparams, batch_slots=1, max_len=64,
+                          prefill_chunk=4)
+        ra = eng.submit(A_PROMPT, max_new_tokens=6)
+        rb = eng.submit(B_PROMPT, max_new_tokens=6)
+        assert [r.done for r in eng.run()] == [True, True]
+        assert ra.generated == fresh(A_PROMPT)
+        assert rb.generated == fresh(B_PROMPT)
+
+
 # ---------------------------------------------------------------------------
 # Seed bugs 2-4: typed request errors instead of engine crashes
 # ---------------------------------------------------------------------------
@@ -566,6 +650,7 @@ class TestStepScopes:
         ("h2o-danube-1.8b", {"cache", "attention", "mlp", "head"}),
         ("mamba2-780m", {"cache", "ssd", "head"}),
         ("hymba-1.5b", {"cache", "attention", "ssd", "mlp", "head"}),
+        ("granite-4.0-h-micro", {"cache", "attention", "ssd", "mlp", "head"}),
     ])
     def test_each_family_carries_its_scopes(self, name, scopes):
         import re
